@@ -145,7 +145,7 @@ test-sharded:
 	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
 	$(PY) -m pytest tests/test_sharded_state.py -q -p no:cacheprovider
 
-## warm-compile: AOT-compile every verifier shape bucket into ./datadir's
+## warm-compile: AOT-compile every verifier shape bucket into the
 ## persistent compile cache (deploy-time warm pass; `cli warm`)
 warm-compile:
-	$(PY) -m lighthouse_tpu.cli warm --datadir $${DATADIR:-./datadir}
+	$(PY) -m lighthouse_tpu.cli warm

@@ -210,18 +210,18 @@ def build_beacon_node(args):
     from .validator_client.beacon_node import InProcessBeaconNode
 
     preset, spec = _spec_preset(args)
+    # persistent XLA compile cache: the verifier's stage compiles are
+    # paid once per checkout, not once per process (utils/compile_cache
+    # decides the location; disk-warm shapes surface as
+    # tpu_compile_cache_hits_total on restart)
+    from .utils.compile_cache import arm as _arm_compile_cache
+
+    _arm_compile_cache()
     if args.datadir:
         import os
 
         # held (via the args reference) for the life of the node
         args._datadir_lock = _lock_datadir(args.datadir)
-        # persistent XLA compile cache under the datadir: the 70-360s
-        # per-shape verifier compile is paid once per binary, not once
-        # per process (utils/compile_cache.py; disk-warm shapes surface
-        # as tpu_compile_cache_hits_total on restart)
-        from .utils.compile_cache import arm as _arm_compile_cache
-
-        _arm_compile_cache(os.path.join(args.datadir, "compile_cache"))
         native_path = os.path.join(args.datadir, "chain.db")
         if os.path.isdir(args.datadir) and not os.path.exists(
             native_path
@@ -320,7 +320,7 @@ def cmd_bn(args):
     node, server = build_beacon_node(args)
     if getattr(args, "warm_compile", False):
         # warm BEFORE serving: every bucketed verifier shape compiles (or
-        # loads from the armed datadir cache) now, so the first slot's
+        # loads from the armed compile cache) now, so the first slot's
         # batches hit only warm executables
         from .crypto.bls.backends.jax_tpu import warm_compile
 
@@ -917,17 +917,13 @@ def cmd_tools(args):
 
 def cmd_warm(args):
     """Standalone AOT bucket warm-up (deploy step): arm the persistent
-    compile cache under the datadir and compile every verifier shape
-    bucket into it, so the NEXT process (the node) starts fully warm --
-    zero tpu_compile_cache_misses_total during slots."""
-    import os
+    compile cache and compile every verifier shape bucket into it, so
+    the NEXT process (the node) starts fully warm -- zero
+    tpu_compile_cache_misses_total during slots."""
+    from .utils.compile_cache import arm as _arm_compile_cache
 
+    _arm_compile_cache()
     from .crypto.bls.backends.jax_tpu import warm_compile
-
-    if args.datadir:
-        from .utils.compile_cache import arm as _arm_compile_cache
-
-        _arm_compile_cache(os.path.join(args.datadir, "compile_cache"))
 
     buckets = None
     if args.bucket:
@@ -1146,13 +1142,9 @@ def main(argv=None) -> int:
 
     warm = sub.add_parser(
         "warm",
-        help="AOT-compile every verifier shape bucket into the datadir's "
+        help="AOT-compile every verifier shape bucket into the "
              "persistent compile cache (deploy-time warm pass)",
     )
-    warm.add_argument("--datadir", default=None,
-                      help="arm the persistent compile cache under this "
-                           "datadir (same location `bn` uses); omit for "
-                           "an in-process-only warm")
     warm.add_argument("--bucket", action="append", default=None,
                       metavar="N,K,M",
                       help="bucketed (sets, pubkeys, messages) shape to "

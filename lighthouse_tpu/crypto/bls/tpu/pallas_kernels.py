@@ -20,8 +20,9 @@ Kernel inventory (all opt-in via LIGHTHOUSE_TPU_PALLAS=1):
   miller_add_step      fused Miller addition: madd-2007-bl + chord line
                        + sparse mul_by_line
 
-Off-TPU backends run every kernel in interpreter mode, which the
-differential tests use to pin bit-exactness against the XLA path.
+The CPU backend runs every kernel in interpreter mode, which the
+differential tests use to pin bit-exactness against the XLA path; any
+backend that is neither the CPU nor a TPU is refused (`_interpret`).
 
 BIT-IDENTITY CONTRACT: the in-kernel field library below (`_k*` helpers)
 transcribes the EXACT formula and reduction schedule of the lax path --
@@ -51,8 +52,9 @@ from . import limbs as L
 W = L.W
 BLOCK_ROWS = 256  # batch rows per VMEM block (256x35 int32 ~ 35 KB/operand)
 # Fused tower/Miller kernels hold a full Fp12 working set per row; keep
-# their blocks smaller so intermediates stay comfortably inside VMEM.
-FUSED_BLOCK_ROWS = 32
+# their blocks small so intermediates fit the 16 MiB of scoped VMEM (at
+# 32 rows the v5e compiler refuses miller_add: 18.49 MiB on its stack).
+FUSED_BLOCK_ROWS = 16
 
 
 # --- in-kernel Fp library (mirrors limbs.py bit-for-bit) --------------------
@@ -60,15 +62,16 @@ FUSED_BLOCK_ROWS = 32
 
 def _fold_round(x, fold_r):
     """limbs._fold_round with the constant matrix passed as a kernel
-    input (Pallas requires captured constants to be explicit operands)."""
+    input (Pallas requires captured constants to be explicit operands).
+    The contraction is a static unroll of VPU multiply-adds, one per
+    high limb: v5e's MXU has no int32 matmul, so Mosaic refuses the
+    einsum. Integer sums are exact, so the result is bit-identical."""
     lo = x[..., : L.NLIMBS]
-    hi = x[..., L.NLIMBS :]
-    acc = lo + jnp.einsum(
-        "...j,jk->...k",
-        hi,
-        fold_r[: hi.shape[-1], : L.NLIMBS],
-        preferred_element_type=jnp.int32,
-    )
+    acc = lo
+    for j in range(x.shape[-1] - L.NLIMBS):
+        acc = acc + x[..., L.NLIMBS + j : L.NLIMBS + j + 1] * fold_r[
+            j : j + 1, : L.NLIMBS
+        ]
     return L.carry3(acc)
 
 
@@ -111,14 +114,29 @@ def _k_lincomb(terms, fold_r):
     return _k_norm(acc, fold_r)
 
 
+def _shift_cols(x, lo: int, width: int):
+    """Place `x` at column offset `lo` of a zero row `width` wide: the
+    shifted add of a schoolbook column sum, built from concatenate
+    because Mosaic has no scatter-add."""
+    parts = []
+    if lo:
+        parts.append(jnp.zeros(x.shape[:-1] + (lo,), x.dtype))
+    parts.append(x)
+    hi = width - lo - x.shape[-1]
+    if hi:
+        parts.append(jnp.zeros(x.shape[:-1] + (hi,), x.dtype))
+    return jnp.concatenate(parts, axis=-1) if len(parts) > 1 else x
+
+
 def _k_mul_cols(a, b):
     """Schoolbook product columns: same integer column sums as
     limbs.mul_columns (the Toeplitz gather), as a static unroll of W
     shifted multiply-adds on the VPU."""
     a, b = jnp.broadcast_arrays(a, b)
-    cols = jnp.zeros(a.shape[:-1] + (2 * W - 1,), jnp.int32)
+    cols = None
     for i in range(W):
-        cols = cols.at[..., i : i + W].add(a[..., i : i + 1] * b)
+        t = _shift_cols(a[..., i : i + 1] * b, i, 2 * W - 1)
+        cols = t if cols is None else cols + t
     return cols
 
 
@@ -128,13 +146,16 @@ def _k_sq_cols(a):
     the exact integers of the generic a*a schoolbook (2 a_i a_j =
     a_i a_j + a_j a_i), so the reduced result is bit-identical to
     limbs.mul(a, a); per-entry intermediates stay < 2^25 << int32."""
-    cols = jnp.zeros(a.shape[:-1] + (2 * W - 1,), jnp.int32)
+    cols = None
     for i in range(W):
-        cols = cols.at[..., 2 * i].add(a[..., i] * a[..., i])
+        # row i covers columns 2i .. i+W-1: a_i^2, then 2 a_i a_j (j > i)
+        row = a[..., i : i + 1] * a[..., i:]
         if i + 1 < W:
-            cols = cols.at[..., 2 * i + 1 : i + W].add(
-                2 * a[..., i : i + 1] * a[..., i + 1 :]
+            row = row + jnp.concatenate(
+                [jnp.zeros_like(row[..., :1]), row[..., 1:]], axis=-1
             )
+        t = _shift_cols(row, 2 * i, 2 * W - 1)
+        cols = t if cols is None else cols + t
     return cols
 
 
@@ -533,6 +554,19 @@ def _sq_call(interpret: bool, block_rows: int):
     return call
 
 
+def _interpret() -> bool:
+    """Interpret mode on the CPU (the differential tests), compiled
+    kernels on a TPU, and a refusal anywhere else: a backend that is
+    neither must never run these kernels through the interpreter and
+    pass that off as device work."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels need a TPU or the CPU, not {backend!r}")
+
+
 def _block_rows(n: int, cap: int) -> int:
     """Size the block to the batch, rounded to the f32 sublane tile of 8,
     so a 5-row op is not padded to the cap."""
@@ -559,7 +593,7 @@ def fp_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     padded = -(-n // block_rows) * block_rows
     a2 = _pad_rows(a2, padded)
     b2 = _pad_rows(b2, padded)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
     out = _mul_call(interpret, block_rows)(a2, b2)
     return out[:n].reshape(*lead, W)
 
@@ -573,7 +607,7 @@ def fp_sq(a: jnp.ndarray) -> jnp.ndarray:
     block_rows = _block_rows(n, BLOCK_ROWS)
     padded = -(-n // block_rows) * block_rows
     a2 = _pad_rows(a2, padded)
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
     out = _sq_call(interpret, block_rows)(a2)
     return out[:n].reshape(*lead, W)
 
@@ -700,7 +734,7 @@ def _run_fused(name: str, ins):
     block_rows = _block_rows(n, FUSED_BLOCK_ROWS)
     padded = -(-n // block_rows) * block_rows
     ins = [_pad_rows(x, padded) for x in ins]
-    interpret = jax.default_backend() != "tpu"
+    interpret = _interpret()
     outs = _fused_call(name, interpret, block_rows)(*ins)
     return [o[:n] for o in outs]
 

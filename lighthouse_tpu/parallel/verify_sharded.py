@@ -19,19 +19,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-
-    SHARD_MAP_NATIVE = True
-except ImportError:  # pre-0.6 jax: the experimental namespace. The
-    # import must not hard-fail -- MeshVerifier's breaker mechanics and
-    # single-device path work everywhere; only the >1-device programs
-    # need shard_map itself.
-    from jax.experimental.shard_map import shard_map
-
-    SHARD_MAP_NATIVE = False
 
 from ..crypto.bls.backends.jax_tpu import (
     verify_body,
@@ -102,10 +91,7 @@ def make_sharded_gather(mesh: Mesh):
 
     specs = (P(VALIDATOR_AXIS), P())
     kw = dict(mesh=mesh, in_specs=specs, out_specs=P())
-    try:
-        body = shard_map(gather_fn, check_vma=False, **kw)
-    except TypeError:  # pre-0.6 jax spells the flag check_rep
-        body = shard_map(gather_fn, check_rep=False, **kw)
+    body = shard_map(gather_fn, check_vma=False, **kw)
     fn = jax.jit(body)
 
     # a plain wrapper because jit objects reject attribute assignment
@@ -129,10 +115,7 @@ def make_sharded_verify(mesh: Mesh):
         u, pk, sig, r, real, axis_name=AXIS
     )
     kw = dict(mesh=mesh, in_specs=(spec,) * 5, out_specs=rep)
-    try:
-        body = shard_map(shard_fn, check_vma=False, **kw)
-    except TypeError:  # pre-0.6 jax spells the flag check_rep
-        body = shard_map(shard_fn, check_rep=False, **kw)
+    body = shard_map(shard_fn, check_vma=False, **kw)
     return jax.jit(body)
 
 
@@ -158,10 +141,7 @@ def make_sharded_verify_grouped(mesh: Mesh):
         )
 
     kw = dict(mesh=mesh, in_specs=specs, out_specs=rep)
-    try:
-        body = shard_map(shard_fn, check_vma=False, **kw)
-    except TypeError:  # pre-0.6 jax spells the flag check_rep
-        body = shard_map(shard_fn, check_rep=False, **kw)
+    body = shard_map(shard_fn, check_vma=False, **kw)
     fn = jax.jit(body)
 
     # a plain wrapper because jit objects reject attribute assignment

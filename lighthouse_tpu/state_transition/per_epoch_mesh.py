@@ -18,7 +18,7 @@ verification). Two `shard_map` programs do the per-validator work:
     penalties, balance application) with NO collectives at all.
 
 Both run with int64/uint64 semantics identical to the numpy path (the
-passes execute under `jax.experimental.enable_x64`; floor division of
+passes execute under `jax.enable_x64(True)`; floor division of
 non-negative 64-bit quantities matches the spec's integer arithmetic
 exactly). Rare per-validator paths -- ejections, the FIFO activation
 queue, slashing hits, hysteresis crossers -- and the surgical tree-cache
@@ -38,7 +38,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..types import FAR_FUTURE_EPOCH, GENESIS_EPOCH
@@ -183,16 +182,10 @@ def _build_programs(mesh):
     def wrap(body, n_col_args, n_rep_args, n_out_cols):
         specs = (col,) * n_col_args + (rep,) * n_rep_args
         out_specs = rep if n_out_cols == 0 else (col,) * n_out_cols
-        try:
-            mapped = shard_map(
-                body, mesh=mesh, in_specs=specs, out_specs=out_specs,
-                check_vma=False,
-            )
-        except TypeError:  # pre-0.6 jax spells the flag check_rep
-            mapped = shard_map(
-                body, mesh=mesh, in_specs=specs, out_specs=out_specs,
-                check_rep=False,
-            )
+        mapped = shard_map(
+            body, mesh=mesh, in_specs=specs, out_specs=out_specs,
+            check_vma=False,
+        )
         return jax.jit(mapped)
 
     sums = wrap(sums_body, 6, 1, 0)
@@ -262,7 +255,7 @@ def process_epoch_altair_mesh(state, preset: Preset, spec, devices=None) -> None
     col_sharding = NamedSharding(mesh, P(VALIDATOR_AXIS))
     rep_sharding = NamedSharding(mesh, P())
 
-    with enable_x64():
+    with jax.enable_x64(True):
         def shard(arr):
             return jax.device_put(arr, col_sharding)
 
@@ -329,7 +322,7 @@ def process_epoch_altair_mesh(state, preset: Preset, spec, devices=None) -> None
 
     # 2-3. inactivity scores + flag rewards/penalties: ONE elementwise
     # sharded pass, no collectives
-    with enable_x64():
+    with jax.enable_x64(True):
         pu = jax.device_put(
             np.array(
                 [

@@ -11,6 +11,7 @@ replay drops uncommitted batches and torn tails."""
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,29 +23,37 @@ _SRC = os.path.join(
     "native",
     "kvstore.cc",
 )
-_LIB_PATH = os.path.join(os.path.dirname(_SRC), "libkvstore.so")
 _BUILD_LOCK = threading.Lock()
 _LIB = None
 
 
+def _lib_path() -> str:
+    """The shared library built from THIS kvstore.cc: keyed on a hash of
+    the source, so a checkout never loads a library built from other
+    source, whatever the files' mtimes say."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(os.path.dirname(_SRC), f"libkvstore-{digest}.so")
+
+
 def _build_lib() -> str:
+    lib = _lib_path()
     with _BUILD_LOCK:
-        if os.path.exists(_LIB_PATH) and os.path.getmtime(
-            _LIB_PATH
-        ) >= os.path.getmtime(_SRC):
-            return _LIB_PATH
+        if os.path.exists(lib):
+            return lib
+        # built under a process-private name and renamed into place, so
+        # a concurrent process never loads a half-written library
+        tmp = f"{lib}.{os.getpid()}.tmp"
         # lint: allow[blocking-under-lock] -- the build lock exists to
         # serialize exactly this one-time g++ compile; every later call
-        # takes the mtime fast path above
+        # takes the exists() fast path above
         subprocess.run(
-            [
-                "g++", "-O2", "-shared", "-fPIC", "-std=c++17",
-                _SRC, "-o", _LIB_PATH,
-            ],
+            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp],
             check=True,
             capture_output=True,
         )
-        return _LIB_PATH
+        os.replace(tmp, lib)
+        return lib
 
 
 # key pointer MUST be c_void_p: c_char_p would NUL-truncate before

@@ -1,30 +1,28 @@
 """Persistent on-disk XLA compilation cache, generalized into the backend.
 
-The verifier's XLA programs cost 70-360 s to compile per shape bucket
-(BENCH_r04/r05), and until now only bench.py / warm_tpu.py armed JAX's
-persistent compilation cache (the warm-cache trick in __graft_entry__).
-This module is that trick promoted to a first-class backend facility:
+The verifier's stage programs take tens of seconds each to compile for
+a TPU, so every entry point arms JAX's persistent compilation cache
+before its first jit. This module is the ONE place that decides where
+that cache lives:
 
-  * ``arm(root)`` points ``jax_compilation_cache_dir`` at a partition
-    under ``root`` -- a node passes ``<datadir>/compile_cache`` (cli.py),
-    entry-point scripts pass the repo-level ``.jax_cache`` -- so compiled
-    executables are paid for once per binary, not once per process.
-  * Partitions are keyed on the backend platform, and CPU partitions are
-    additionally fingerprinted by host CPU features: XLA:CPU's AOT loader
-    aborts on entries compiled for another machine's feature set, and
-    remote-TPU sessions compile CPU stubs on the REMOTE host. A different
-    host or platform simply starts a fresh partition -- cross-poisoning
-    is impossible by construction.
-  * A sidecar ``shapes.json`` registry records every bucketed batch
-    shape whose executables a process finished compiling under the
-    partition: the backend LOOKS a shape up at marshal time
-    (``shape_on_disk``, feeding ``tpu_compile_cache_hits_total`` for
-    process-cold but disk-warm shapes) and WRITES it only after the
-    shape's first dispatch has returned (``record_shape``) -- jit
-    compilation is synchronous at call time, so by then the executables
-    exist and are persisted. A process killed mid-compile therefore
-    never registers the shape, and the next process honestly counts a
-    miss.
+  * ``JAX_COMPILATION_CACHE_DIR`` set: the cache is exactly that
+    directory, and no other directory is set in code.
+  * Otherwise: the checkout's fixed ``.jax_cache``, partitioned by the
+    platform the process compiles for (``tpu``, or ``cpu-<host CPU
+    fingerprint>``: XLA:CPU's AOT loader aborts on entries compiled for
+    another machine's feature set). The path never carries a temp name,
+    a pid or a time -- the path is part of the cache's key, so a
+    directory that moves never hits.
+
+A sidecar ``shapes.json`` registry in the same directory records every
+bucketed batch shape whose executables a process finished compiling:
+the backend LOOKS a shape up at marshal time (``shape_on_disk``,
+feeding ``tpu_compile_cache_hits_total`` for process-cold but disk-warm
+shapes) and WRITES it only after the shape's first dispatch has
+returned (``record_shape``) -- jit compilation is synchronous at call
+time, so by then the executables exist and are persisted. A process
+killed mid-compile therefore never registers the shape, and the next
+process honestly counts a miss.
 
 Registry updates are atomic-rename writes; concurrent processes can lose
 an update (the next completed dispatch re-records it), which only ever
@@ -36,6 +34,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import threading
 
 _ARMED_DIR: str | None = None
 
@@ -55,6 +54,15 @@ def host_cpu_fingerprint() -> str:
     return hashlib.sha256(_platform.processor().encode()).hexdigest()[:10]
 
 
+# the checkout root: three levels above lighthouse_tpu/utils/
+CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
 def partition(root: str) -> str:
     """The backend-keyed cache partition under ``root`` for the platform
     the current process will compile for (resolved WITHOUT initializing
@@ -71,17 +79,26 @@ def partition(root: str) -> str:
     return os.path.join(root, sub)
 
 
-def arm(root: str) -> str:
-    """Point JAX's persistent compilation cache at this root's partition
-    and remember it for shape-registry lookups. Returns the partition
-    directory. Set ``LIGHTHOUSE_TPU_COMPILE_CACHE=0`` to refuse (test
-    suites, debugging)."""
+def cache_dir() -> str:
+    """Where this process's persistent compile cache lives:
+    ``JAX_COMPILATION_CACHE_DIR`` when set, else the platform partition
+    of the checkout's ``.jax_cache``."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or partition(
+        CHECKOUT_CACHE
+    )
+
+
+def arm() -> str:
+    """Point JAX's persistent compilation cache at `cache_dir()` and
+    remember it for shape-registry lookups. Returns the directory. Set
+    ``LIGHTHOUSE_TPU_COMPILE_CACHE=0`` to refuse (test suites,
+    debugging)."""
     global _ARMED_DIR
     if os.environ.get("LIGHTHOUSE_TPU_COMPILE_CACHE") == "0":
         return ""
     import jax
 
-    part = partition(root)
+    part = cache_dir()
     os.makedirs(part, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", part)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
@@ -139,7 +156,7 @@ def record_shape(key: tuple, part: str | None = None) -> None:
         return
     shapes.add(name)
     path = _registry_path(part)
-    tmp = f"{path}.tmp.{os.getpid()}"
+    tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
     try:
         with open(tmp, "w") as f:
             json.dump(sorted(shapes), f)

@@ -1,7 +1,7 @@
 """Per-stage steady-state timing of the staged TPU verifier (warm cache).
 
 Prints one line per stage at the bench shape so optimization effort goes
-where the time is. Run after warm_tpu.py.
+where the time is. Run after `python -m lighthouse_tpu.cli warm`.
 """
 
 import sys
@@ -9,9 +9,10 @@ import time
 
 sys.path.insert(0, ".")
 
-from __graft_entry__ import _arm_compilation_cache, _example_batch
+from __graft_entry__ import _example_batch
+from lighthouse_tpu.utils.compile_cache import arm
 
-_arm_compilation_cache()
+arm()
 
 import jax
 

@@ -11,6 +11,8 @@ drive the registry with explicit directories instead.
 import json
 import os
 
+import pytest
+
 from lighthouse_tpu.utils import compile_cache as CC
 from lighthouse_tpu.utils.metrics import (
     TPU_COMPILE_CACHE_HITS,
@@ -56,11 +58,37 @@ class TestShapeRegistry:
         finally:
             CC._ARMED_DIR = saved
 
-    def test_arm_refused_by_env_kill_switch(self, tmp_path, monkeypatch):
+    def test_arm_refused_by_env_kill_switch(self, monkeypatch):
         monkeypatch.setenv("LIGHTHOUSE_TPU_COMPILE_CACHE", "0")
         saved = CC._ARMED_DIR
-        assert CC.arm(str(tmp_path)) == ""
+        assert CC.arm() == ""
         assert CC._ARMED_DIR == saved  # untouched: nothing was armed
+
+    @pytest.mark.parametrize("from_env", [True, False])
+    def test_arm_location(self, tmp_path, monkeypatch, from_env):
+        """JAX_COMPILATION_CACHE_DIR, when set, is the cache and the only
+        one; otherwise the checkout's fixed .jax_cache partition."""
+        import jax
+        from jax.experimental.compilation_cache import compilation_cache
+
+        monkeypatch.delenv("LIGHTHOUSE_TPU_COMPILE_CACHE", raising=False)
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            want = str(tmp_path)
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            want = CC.partition(os.path.join(root, ".jax_cache"))
+        saved = (CC._ARMED_DIR, jax.config.jax_compilation_cache_dir)
+        try:
+            assert CC.arm() == want
+            assert CC.armed_dir() == want
+            assert jax.config.jax_compilation_cache_dir == want
+            assert os.path.isdir(want)
+        finally:
+            CC._ARMED_DIR = saved[0]
+            jax.config.update("jax_compilation_cache_dir", saved[1])
+            compilation_cache.reset_cache()
 
     def test_partition_is_platform_keyed(self, tmp_path):
         part = CC.partition(str(tmp_path))

@@ -17,15 +17,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-if not hasattr(jax, "shard_map"):
-    # seed parity on pre-0.6 jax: this file previously failed collection
-    # on `from jax import shard_map`; a clean skip keeps old environments
-    # from burning the suite budget compiling experimental shard programs
-    pytest.skip(
-        "jax too old for the production shard_map path",
-        allow_module_level=True,
-    )
-
 from lighthouse_tpu.crypto.bls import (
     AggregateSignature,
     SecretKey,

@@ -279,3 +279,18 @@ def test_env_switch_rebinds_mul(monkeypatch):
         assert (out == ref).all()
     finally:
         sys.modules.update(saved)
+
+
+@pytest.mark.parametrize(
+    "backend,interpret",
+    [("cpu", True), ("tpu", False), ("gpu", None)],
+)
+def test_interpret_mode_only_on_cpu(monkeypatch, backend, interpret):
+    """Interpret mode on the CPU, compiled kernels on a TPU, and a refusal
+    elsewhere: no backend silently runs the interpreter as device work."""
+    monkeypatch.setattr(PK.jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="TPU or the CPU"):
+            PK._interpret()
+    else:
+        assert PK._interpret() is interpret

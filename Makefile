@@ -5,8 +5,8 @@ PY ?= python
 .PHONY: lint lint-changed lint-baseline test test-lint test-chaos \
 	test-crash test-scenario test-serving test-speculate test-kernels \
 	test-fuzz fuzz test-adversary fuzz-adversary bench-serving \
-	bench-speculate bench-latency bench-scale test-sharded warm-compile \
-	ledger-report
+	bench-speculate bench-latency bench-scale test-sharded test-multichip \
+	warm-compile ledger-report
 
 ## lint: per-file + interprocedural project pass (tools/lint, stdlib-only);
 ## times itself and fails over the 10s budget so it never becomes a
@@ -144,6 +144,23 @@ test-sharded:
 	JAX_PLATFORMS=cpu \
 	XLA_FLAGS=--xla_force_host_platform_device_count=4 \
 	$(PY) -m pytest tests/test_sharded_state.py -q -p no:cacheprovider
+
+## test-multichip: the sharded verifier against the single-device kernel
+## on the 8-device virtual mesh, slow parity tests included (each
+## compiles the whole verify_body; tier-1 runs only the fast twins). One
+## process a slow class, as XLA:CPU has aborted after about six of these
+## compiles in one process; a first compile takes up to 15 minutes, over
+## the suite's per-test time limit
+MULTICHIP_SLOW = TestShardedMatchesSingleDevice TestGeneratorPairCountedOnce \
+	TestMeshVerifierRealKernel TestGroupedMeshReduction
+test-multichip:
+	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_multichip.py -q \
+		-m 'not slow' -p no:cacheprovider
+	rc=0; for c in $(MULTICHIP_SLOW); do \
+		JAX_PLATFORMS=cpu LIGHTHOUSE_TPU_TEST_TIMEOUT_S=1800 \
+		$(PY) -m pytest "tests/test_multichip.py::$$c" -q -m slow \
+			-p no:cacheprovider || rc=1; \
+	done; exit $$rc
 
 ## warm-compile: AOT-compile every verifier shape bucket into the
 ## persistent compile cache (deploy-time warm pass; `cli warm`)

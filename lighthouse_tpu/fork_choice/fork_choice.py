@@ -78,19 +78,27 @@ class ForkChoice:
     # -- time (fork_choice.rs on_tick) --------------------------------------
 
     def on_tick(self, slot: int) -> None:
-        while self.current_slot < slot:
-            self.current_slot += 1
-            self._dequeue_attestations()
-            # proposer boost expires at the start of the next slot
-            self.proto.proposer_boost_root = None
-            # a plain slot tick lands at the slot start: timely until told
-            # otherwise by on_tick_time
-            self.seconds_into_slot = 0
-            # epoch-boundary pull-up (fork_choice.rs on_tick): what was
-            # unrealized last epoch is realized now, even if no block has
-            # imported since -- the late-epoch justification race
-            if self.current_slot % self.preset.slots_per_epoch == 0:
-                self._realize_unrealized()
+        """Advance to `slot` in one step, however many slots it skips.
+        Attestations are queued only in the slot they arrive in, so the
+        queue is in slot order and one dequeue applies them in the order
+        a per-slot walk would; nothing is queued or left unrealized by
+        the skipped slots, so they need no pass of their own."""
+        if slot <= self.current_slot:
+            return
+        spe = self.preset.slots_per_epoch
+        crosses_epoch = slot // spe > self.current_slot // spe
+        self.current_slot = slot
+        self._dequeue_attestations()
+        # proposer boost expires at the start of the next slot
+        self.proto.proposer_boost_root = None
+        # a plain slot tick lands at the slot start: timely until told
+        # otherwise by on_tick_time
+        self.seconds_into_slot = 0
+        # epoch-boundary pull-up (fork_choice.rs on_tick): what was
+        # unrealized last epoch is realized now, even if no block has
+        # imported since -- the late-epoch justification race
+        if crosses_epoch:
+            self._realize_unrealized()
 
     def on_tick_time(self, time_s: int, genesis_time: int) -> None:
         """Second-granular tick (spec on_tick): advances the slot AND

@@ -7,8 +7,14 @@ tool (`--chips 4` for the mesh); tests/test_chip_compile.py compiles the
 main-path kernels for a described v5e without one.
 """
 
+import contextlib
+import faulthandler
 import os
+import signal
 import sys
+import threading
+
+import pytest
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
@@ -98,37 +104,118 @@ def pytest_configure(config):
     )
 
 
+# Per-test time limits: a test whose set-up or call runs past its limit
+# fails with a message and a dump of every thread's stack, instead of
+# holding its worker until the whole run is killed. Set-up gets twice the
+# call's limit, as the `staged_verifier` session fixture compiles there
+# (250-315 s with six workers on 8 CPU cores). The alarm's handler runs
+# only between Python bytecodes, so a test stuck inside C++ (an XLA
+# compile) is not failed at its limit: at twice the limit its stacks are
+# dumped and the process ends, which pytest-xdist reports as that test's
+# failure. LIGHTHOUSE_TPU_TEST_TIMEOUT_S changes the limits (0 = off).
+TEST_TIMEOUT_S = int(os.environ.get("LIGHTHOUSE_TPU_TEST_TIMEOUT_S", "300"))
+SETUP_TIMEOUT_S = 2 * TEST_TIMEOUT_S
+# stacks go to the stderr the process started with, which pytest's output
+# capture does not swallow
+_STDERR_FD = os.dup(2)
+
+
+@contextlib.contextmanager
+def _time_limit(item, phase, limit):
+    if limit <= 0 or threading.current_thread() is not threading.main_thread():
+        yield
+        return
+
+    def expired(signum, frame):
+        faulthandler.dump_traceback(file=_STDERR_FD)
+        pytest.fail(
+            f"{item.nodeid}: {phase} ran past its {limit} s time limit "
+            "(LIGHTHOUSE_TPU_TEST_TIMEOUT_S)"
+        )
+
+    previous = signal.signal(signal.SIGALRM, expired)
+    faulthandler.dump_traceback_later(2 * limit, exit=True, file=_STDERR_FD)
+    signal.alarm(limit)
+    try:
+        yield
+    finally:
+        signal.alarm(0)
+        faulthandler.cancel_dump_traceback_later()
+        signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_setup(item):
+    with _time_limit(item, "set-up", SETUP_TIMEOUT_S):
+        yield
+
+
+@pytest.hookimpl(hookwrapper=True)
+def pytest_runtest_call(item):
+    with _time_limit(item, "call", TEST_TIMEOUT_S):
+        yield
+
+
+@pytest.fixture(scope="session")
+def staged_verifier():
+    """The staged device verifier compiled at its smallest shape bucket (4
+    sets, 4 keys, 4 messages), once per worker and during set-up, so that
+    a device-path test spends its time limit on its own shapes only."""
+    from lighthouse_tpu.crypto.bls import SecretKey, SignatureSet
+    from lighthouse_tpu.crypto.bls.backends import jax_tpu
+
+    sk, msg = SecretKey(7), bytes(32)
+    s = SignatureSet.single_pubkey(sk.sign(msg), sk.public_key(), msg)
+    assert jax_tpu.verify_signature_sets([s], seed=0)
+
+
+
+# Test files that compile large XLA programs, in groups that share their
+# programs; the first two share the staged verifier. Without an order,
+# five of the six workers compiled `staged_verifier` (265-315 s of set-up
+# each) and `pytest tests/ -m 'not slow' -n 6 --dist load` took 565-614 s
+# on 8 CPU cores; with one group a worker, two compile it and it took
+# 445 s, the slowest worker being the first group's (418 s; the others
+# 274-339 s).
+COMPILE_GROUPS = (
+    ("test_bls_api", "test_bls_aggregation"),
+    ("test_bls_edge_matrix", "test_pubkey_table", "test_ef_vectors"),
+    ("test_tpu_hash_to_curve", "test_tpu_pairing", "test_known_vectors"),
+    ("test_tpu_tower", "test_tpu_curve", "test_tpu_limbs"),
+    ("test_pallas_kernels", "test_chip_compile"),
+    ("test_sharded_state", "test_multichip"),
+)
+
+
+@pytest.hookimpl(trylast=True)
 def pytest_collection_modifyitems(session, config, items):
-    """Run EVERY XLA-compiling test file FIRST, before anything that
-    spawns server/daemon threads. XLA:CPU compilation (and executable
-    deserialization) segfaults non-deterministically late in a long
-    pytest process once network tests have left daemon threads behind --
-    observed three times at ~60-85% progress inside backend_compile /
-    get_executable_and_time, always under an eager kernel call that runs
-    fine standalone or early. Front-loading all compile-heavy files gives
-    them the young-process slot; pure-Python consensus/network tests run
-    after."""
-    compile_heavy = (
-        "test_chip_compile",  # v5e AOT compiles: libtpu's compiler
-        "test_multichip",  # biggest programs: keep the freshest slot
-        "test_sharded_state",  # shard_map gather + mesh epoch programs
-        "test_tpu_",
-        "test_pallas_kernels",
-        "test_bls_api",
-        "test_bls_aggregation",  # compiles the mega-pairing group stage
-        "test_bls_edge_matrix",
-        "test_bls_adversary",  # slow matrix compiles the staged verifier
-        "test_pubkey_table",
-        "test_known_vectors",
-        "test_ef_vectors",
-        "test_pipeline",
-    )
+    """Start each pytest-xdist worker on one group of compile-heavy files.
 
-    def rank(item):
-        path = str(item.fspath)
-        for i, frag in enumerate(compile_heavy):
-            if frag in path:
-                return i
-        return len(compile_heavy)
-
-    items.sort(key=rank)
+    Under `--dist load` worker i first runs the i-th run of
+    len(items) // workers // 4 consecutive tests (pytest-xdist's
+    LoadScheduling.schedule), and only what follows goes to whichever
+    worker is free. Putting one group at the head of each first run keeps
+    each group on one worker, so no two workers compile the same
+    programs. A file missing from the table that requests
+    `staged_verifier` joins the second group. Runs after `-m` deselection,
+    whose count sets the run length."""
+    workers = int(os.environ.get("PYTEST_XDIST_WORKER_COUNT", "0"))
+    if workers < 2:
+        return
+    run = max(len(items) // workers // 4, 2)
+    group_of = {f: g for g, files in enumerate(COMPILE_GROUPS) for f in files}
+    groups = [[] for _ in COMPILE_GROUPS]
+    light = []
+    for item in items:
+        staged = "staged_verifier" in getattr(item, "fixturenames", ())
+        g = group_of.get(item.path.stem, 1 if staged else None)
+        (light if g is None else groups[g]).append(item)
+    ordered, spill = [], []
+    for g in range(workers):
+        head = groups[g] if g < len(groups) else []
+        spill += head[run:]
+        fill = run - len(head[:run])
+        ordered += head[:run] + light[:fill]
+        light = light[fill:]
+    spill += [i for g in groups[workers:] for i in g]
+    items[:] = ordered + spill + light

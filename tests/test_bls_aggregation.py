@@ -36,6 +36,10 @@ from lighthouse_tpu.crypto.bls.constants import R
 from lighthouse_tpu.utils import metrics as M
 
 
+# device-path tests: the staged verifier compiles in set-up (conftest)
+pytestmark = pytest.mark.usefixtures("staged_verifier")
+
+
 @pytest.fixture(autouse=True)
 def _restore_backend():
     yield

@@ -23,6 +23,9 @@ from lighthouse_tpu.crypto.bls.constants import R
 
 rng = random.Random(7)
 
+# device-path tests: the staged verifier compiles in set-up (conftest)
+pytestmark = pytest.mark.usefixtures("staged_verifier")
+
 
 def keypair():
     sk = SecretKey(rng.randrange(1, R))
@@ -85,8 +88,10 @@ class TestVerify:
         assert not verify_signature_sets([s_bad], seed=2)
 
     def test_batch_mixed_sets(self, backend):
+        # 2 single-key sets, a 2-key set and the forged one: every verify
+        # stays in the 4-set bucket that staged_verifier compiled
         batch = []
-        for i in range(3):
+        for i in range(2):
             sk, pk = keypair()
             msg = bytes([i]) * 32
             batch.append(SignatureSet.single_pubkey(sk.sign(msg), pk, msg))
@@ -145,3 +150,44 @@ class TestVerify:
             assert verify_signature_sets([s])
         finally:
             set_backend("jax_tpu")
+
+
+@pytest.mark.chaos
+def test_midbatch_fault_degrades_to_cpu_oracle_and_reprobes():
+    """Killing the real jax_tpu backend mid-batch completes
+    verify_signature_sets() on the cpu oracle with the unfaulted verdict,
+    and the breaker's half-open probe hands the hot path back to jax_tpu
+    (same 4-set bucket as the tests above, so no new shape compiles)."""
+    from lighthouse_tpu.crypto.bls.backends import cpu, jax_tpu
+    from lighthouse_tpu.crypto.bls.backends.fallback import FallbackBackend
+    from lighthouse_tpu.resilience import (
+        CircuitBreaker,
+        EventLog,
+        FaultPlan,
+        VirtualClock,
+    )
+
+    sets = []
+    for i in range(2):
+        sk, pk = keypair()
+        msg = bytes([i]) * 32
+        sets.append(SignatureSet.single_pubkey(sk.sign(msg), pk, msg))
+    clock, events = VirtualClock(), EventLog()
+    plan = FaultPlan(seed=1, clock=clock, events=events)
+    plan.fail_next("jax_tpu.verify_signature_sets", 1)
+    backend = FallbackBackend(
+        primary=plan.wrap(jax_tpu, "jax_tpu"),
+        fallback=cpu,
+        breaker=CircuitBreaker(
+            clock=clock, failure_threshold=1, reset_timeout_s=10.0,
+            events=events, name="bls_primary",
+        ),
+        events=events,
+    )
+    assert backend.verify_signature_sets(sets, seed=5) is True
+    assert backend.breaker.state == CircuitBreaker.OPEN
+    assert events.kinds().count("bls_fallback") == 1
+    clock.advance(11.0)
+    assert backend.verify_signature_sets(sets, seed=5) is True
+    assert backend.breaker.state == CircuitBreaker.CLOSED
+    assert backend.active_backend_name() == "jax_tpu"

@@ -28,6 +28,9 @@ from lighthouse_tpu.crypto.bls.hash_to_curve_ref import (
 
 BACKENDS = ["cpu", "jax_tpu"]
 
+# device-path tests: the staged verifier compiles in set-up (conftest)
+pytestmark = pytest.mark.usefixtures("staged_verifier")
+
 
 @pytest.fixture(autouse=True)
 def _restore_backend():

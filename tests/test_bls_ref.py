@@ -8,6 +8,8 @@ pairing, and serialization round-trips. Mirrors the intent of
 crypto/bls/tests/tests.rs and testing/ef_tests' bls handlers in the reference.
 """
 
+import random
+
 import pytest
 
 from lighthouse_tpu.crypto.bls.constants import BLS_X, DST, P, R
@@ -28,7 +30,24 @@ class TestParameters:
         assert (P * P) % 16 == 9  # enables the Fp2 sqrt_ratio chain
 
 
+_rng = random.Random(5)
+
+
 class TestFields:
+    @pytest.mark.parametrize(
+        "n",
+        [1, P - 1, 2**380] + [_rng.randrange(2, P - 1) for _ in range(4)],
+        ids=["1", "p-1", "2^380"] + [f"random{i}" for i in range(4)],
+    )
+    def test_fp_inv_matches_fermat(self, n):
+        x = Fp(n)
+        assert x.inv().n == pow(x.n, P - 2, P)
+        assert x * x.inv() == Fp.one()
+
+    def test_fp_inv_of_zero_raises(self):
+        with pytest.raises(ZeroDivisionError):
+            Fp(0).inv()
+
     def test_fp2_inv_mul(self):
         a = Fp2(1234567, 7654321)
         assert a * a.inv() == Fp2.one()

@@ -890,6 +890,7 @@ def test_mini_tree_state_cases(mini_tree):
     assert len(results) == 31
 
 
+@pytest.mark.usefixtures("staged_verifier")
 def test_mini_tree_bls_cases_on_jax_backend(mini_tree):
     set_backend("jax_tpu")
     try:

@@ -2,6 +2,10 @@
 embedded fork-choice test definitions (consensus/proto_array/src/
 fork_choice_test_definition/votes.rs -- no network, pure data)."""
 
+import random
+
+import pytest
+
 from lighthouse_tpu.fork_choice import ProtoArrayForkChoice
 
 GENESIS = b"\x00" * 32
@@ -301,3 +305,107 @@ class TestUnrealizedJustification:
         late_root = h.chain.process_block(signed, strategy=h.strategy)
         assert late_root
         assert h.chain.fork_choice.justified_checkpoint[0] > jc_before[0]
+
+
+def _per_slot_on_tick(fc, slot):
+    """The per-slot walk that ForkChoice.on_tick replaced, kept as the
+    reference for what its one-step catch-up must leave behind."""
+    while fc.current_slot < slot:
+        fc.current_slot += 1
+        fc._dequeue_attestations()
+        fc.proto.proposer_boost_root = None
+        fc.seconds_into_slot = 0
+        if fc.current_slot % fc.preset.slots_per_epoch == 0:
+            fc._realize_unrealized()
+
+
+class TestOnTickCatchUp:
+    """on_tick advances any number of slots in one step and leaves the
+    same store as a tick per slot."""
+
+    N_VALIDATORS = 24
+
+    def _fork_choice(self):
+        from lighthouse_tpu.fork_choice.fork_choice import ForkChoice
+        from lighthouse_tpu.types import MINIMAL, ChainSpec
+
+        fc = ForkChoice(
+            MINIMAL, ChainSpec.minimal(), 0, GENESIS,
+            (0, GENESIS), (0, GENESIS),
+        )
+        fc.justified_balances = [32] * self.N_VALIDATORS
+        return fc
+
+    @staticmethod
+    def _store(fc):
+        try:
+            head = fc.get_head()
+        except Exception as e:  # noqa: BLE001 -- both sides must agree
+            head = repr(e)
+        return (
+            fc.current_slot,
+            list(fc.queued_attestations),
+            fc.justified_checkpoint,
+            fc.finalized_checkpoint,
+            fc.proto.proposer_boost_root,
+            fc.seconds_into_slot,
+            {
+                v: (t.current_root, t.next_root, t.next_epoch)
+                for v, t in fc.proto.votes.items()
+            },
+            [n.weight for n in fc.proto.proto_array.nodes],
+            head,
+        )
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_one_step_matches_per_slot_walk(self, seed):
+        rng = random.Random(seed)
+        fast, slow = self._fork_choice(), self._fork_choice()
+        spe = fast.preset.slots_per_epoch
+        roots = [GENESIS]
+        for step, gap in enumerate([1, 7, 1, 1000, 7, 1, 1000, 7, 1, 1]):
+            slot = fast.current_slot
+            if step % 3 != 2:
+                # a block at the current slot, on a random known parent
+                r = root(len(roots))
+                parent = rng.choice(roots)
+                for fc in (fast, slow):
+                    fc.proto.process_block(
+                        slot, r, parent, fc.justified_checkpoint,
+                        fc.finalized_checkpoint,
+                    )
+                    fc.proto.proposer_boost_root = r
+                roots.append(r)
+            for _ in range(rng.randrange(1, 5)):
+                att_slot = slot - rng.randrange(0, 2) if slot else 0
+                voters = rng.sample(range(self.N_VALIDATORS), 5)
+                target = rng.choice(roots)
+                for fc in (fast, slow):
+                    fc.on_attestation(att_slot, voters, target)
+            if step % 2:
+                # an imported state justified more than the store holds
+                epoch = slot // spe
+                for fc in (fast, slow):
+                    fc.unrealized_justified_checkpoint = (
+                        epoch, roots[-1]
+                    )
+                    fc.unrealized_finalized_checkpoint = (
+                        max(epoch - 1, 0), roots[-2 if len(roots) > 1 else 0]
+                    )
+            fast.on_tick(slot + gap)
+            _per_slot_on_tick(slow, slot + gap)
+            assert self._store(fast) == self._store(slow)
+        assert fast.current_slot > 10 * spe
+
+    def test_tick_across_1e8_slots_returns_within_a_second(self):
+        import time
+
+        fc = self._fork_choice()
+        fc.on_attestation(0, [1, 2], GENESIS)
+        fc.unrealized_justified_checkpoint = (3, GENESIS)
+        t0 = time.perf_counter()
+        fc.on_tick(10**8)
+        assert time.perf_counter() - t0 < 1.0
+        assert fc.current_slot == 10**8
+        assert fc.queued_attestations == []
+        assert fc.justified_checkpoint == (3, GENESIS)

@@ -10,6 +10,11 @@ map-reduce being sharded, block_signature_verifier.rs:374-384):
     with infinity-signature rows still verifies),
   * the generator pair is counted exactly once across shards (a wrong
     per-shard inclusion flips the pairing product and rejects everything).
+
+Those parity tests compile the whole verify_body, alone and under
+shard_map, for minutes each on the CPU, so they are marked `slow` and run
+with `make test-multichip`; tier-1 keeps the fast wiring twin and the
+routing tests.
 """
 
 import numpy as np
@@ -82,6 +87,44 @@ def valid_args():
     return _marshal(sets, N_DEV)
 
 
+class TestShardedWiring:
+    """Fast twin of the slow classes below, which compile the whole
+    verify_body under shard_map: `make_sharded_verify` with a cheap body
+    in verify_body's seat. Each shard must see its own slice of every
+    argument, the body must run with the mesh axis, exactly one shard
+    must own the generator pair, and the verdict must come back as one
+    replicated bool."""
+
+    @pytest.mark.parametrize("tamper", [False, True])
+    def test_each_shard_gets_its_sets_and_the_verdict_is_replicated(
+        self, mesh, valid_args, monkeypatch, tamper
+    ):
+        from lighthouse_tpu.parallel import verify_sharded as VS
+
+        local_rows = []
+
+        def probe_body(u, pk, sig, r, real, axis_name=None):
+            local_rows.append({a.shape[0] for a in (u, pk, sig, r, real)})
+            # real rows carry an odd weight: a shard handed another
+            # shard's rows still passes, so the row count is checked too
+            local = jnp.all(real == ((r[:, 1] & 1) == 1))
+            first = (jax.lax.axis_index(axis_name) == 0).astype(jnp.int32)
+            return jnp.all(jax.lax.all_gather(local, axis_name)) & (
+                jax.lax.psum(first, axis_name) == 1
+            )
+
+        monkeypatch.setattr(VS, "verify_body", probe_body)
+        u, pk, sig, scalars, real = valid_args
+        if tamper:
+            # the last shard's set loses its odd weight
+            scalars = scalars.at[N_DEV - 1, 1].set(2)
+        out = VS.make_sharded_verify(mesh)(u, pk, sig, scalars, real)
+        assert local_rows == [{real.shape[0] // N_DEV}]
+        assert out.shape == () and out.dtype == jnp.bool_
+        assert bool(out) is not tamper
+
+
+@pytest.mark.slow
 class TestShardedMatchesSingleDevice:
     def test_valid_batch_accepted_and_matches(self, sharded, valid_args):
         assert bool(verify_jit(*valid_args)) is True
@@ -113,6 +156,7 @@ class TestShardedMatchesSingleDevice:
         assert bool(sharded(*args)) is True
 
 
+@pytest.mark.slow
 class TestGeneratorPairCountedOnce:
     def test_include_gen_only_on_first_shard(self, mesh, valid_args):
         """If every shard contributed the (-g1, sum r sig) pair, the
@@ -171,6 +215,7 @@ class TestGeneratorPairCountedOnce:
         assert single == multi == False  # noqa: E712
 
 
+@pytest.mark.slow
 class TestMeshVerifierRealKernel:
     """MeshVerifier (parallel/verify_sharded.py) driving the REAL shard
     programs: the resilient promotion of this file's sharded kernel into
@@ -324,6 +369,55 @@ class TestGroupedMeshReduction:
             True, True, False, False,
         ]
 
+    @pytest.mark.parametrize("n_dev", [N_GROUP_DEV, N_DEV])
+    @pytest.mark.parametrize("tamper", [False, True])
+    def test_grouped_shards_get_their_sets_and_every_message(
+        self, grouped_mb, monkeypatch, n_dev, tamper
+    ):
+        """Fast twin of the two slow grouped parity tests below:
+        `make_sharded_verify_grouped`, placed by the DeviceExecutor from
+        its `arg_specs`, with a cheap body in verify_body_grouped's seat.
+        The message draws and mask must reach every shard whole, the
+        per-set arrays and the membership mask must be sliced, the group
+        sizes summed across shards must match the replicated message
+        mask, and the verdict must come back as one replicated bool."""
+        from lighthouse_tpu.parallel import DeviceExecutor
+        from lighthouse_tpu.parallel import verify_sharded as VS
+
+        shapes = []
+
+        def probe_body(
+            u, pk, sig, r, real, member, msg_real, axis_name=None
+        ):
+            per_set = (pk, sig, r, real, member)
+            shapes.append((u.shape, msg_real.shape, {a.shape[0] for a in per_set}))
+            same_u = jnp.all(jax.lax.all_gather(u, axis_name) == u[None])
+            # each real local set sits in exactly one message group
+            local = jnp.all(member.sum(axis=1) == real)
+            sizes = jax.lax.psum(member.sum(axis=0), axis_name)
+            groups = jnp.all((sizes > 0) == msg_real) & (
+                sizes.sum() == jax.lax.psum(real.sum(), axis_name)
+            )
+            return jnp.all(jax.lax.all_gather(local, axis_name)) & (
+                same_u & groups
+            )
+
+        monkeypatch.setattr(VS, "verify_body_grouped", probe_body)
+        args = self._args(grouped_mb)
+        if tamper:
+            # the last set moves to a padded message row: its shard's
+            # rows stay consistent, only the cross-shard sizes disagree
+            moved = jnp.zeros_like(args[5][-1]).at[-1].set(True)
+            args = args[:5] + (args[5].at[-1].set(moved), args[6])
+        devices = jax.devices("cpu")[:n_dev]
+        call = VS.make_sharded_verify_grouped(sets_mesh(devices))
+        out = DeviceExecutor().run(call, args, devices)
+        m_b = grouped_mb.msg_real.shape[0]
+        assert shapes == [(grouped_mb.u.shape, (m_b,), {N_DEV // n_dev})]
+        assert out.shape == () and out.dtype == jnp.bool_
+        assert bool(out) is not tamper
+
+    @pytest.mark.slow
     def test_grouped_mesh_matches_single_device_aggregated(
         self, grouped_sharded, grouped_mb, grid_mb
     ):
@@ -337,6 +431,7 @@ class TestGroupedMeshReduction:
         assert bool(B.verify_grouped_jit(*self._args(grouped_mb))) is True
         assert bool(grouped_sharded(*self._args(grouped_mb))) is True
 
+    @pytest.mark.slow
     def test_tampered_batch_rejected_on_every_path(
         self, grouped_sharded, grouped_mb, grid_mb
     ):
@@ -369,14 +464,18 @@ class TestGroupedMeshReduction:
         args = self._args(grouped_mb)
         assert MeshVerifier._n_sets(args) == N_DEV
         seen = []
+        # the executor records the call instead of compiling the grouped
+        # monolith, whose verdict the slow parity tests above check
         mv = MeshVerifier(
             devices=jax.devices("cpu")[:1],
             executor=SimpleNamespace(
-                run=lambda fn, a, devs: seen.append(fn) or fn(*a)
+                run=lambda fn, a, devs: seen.append((fn, a))
+                or jnp.asarray(True)
             ),
         )
         assert bool(mv.verify(args)) is True
-        assert seen == [B.verify_grouped_jit]
+        assert len(seen) == 1 and seen[0][0] is B.verify_grouped_jit
+        assert all(x is y for x, y in zip(seen[0][1], args, strict=True))
 
     def test_dispatch_counts_message_pairs_not_set_pairs(
         self, grouped_sets, monkeypatch

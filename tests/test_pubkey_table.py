@@ -22,6 +22,10 @@ from lighthouse_tpu.crypto.bls.backends import jax_tpu
 from lighthouse_tpu.types.interop import interop_keypair
 
 
+# device-path tests: the staged verifier compiles in set-up (conftest)
+pytestmark = pytest.mark.usefixtures("staged_verifier")
+
+
 def _registry_state(n):
     return SimpleNamespace(
         validators=[
@@ -82,7 +86,8 @@ def test_hot_path_does_no_per_key_limb_packing(monkeypatch):
 
 def test_mixed_batch_falls_back_to_host_packing():
     cache = ValidatorPubkeyCache(_registry_state(8))
-    sets = _tagged_sets(cache)
+    # three tagged sets and the untagged one fill the 4-set bucket
+    sets = _tagged_sets(cache, n_sets=3)
     # one untagged key (e.g. a deposit outside the registry): generic path
     sk, pk = interop_keypair(100)
     msg = b"\x42" * 32

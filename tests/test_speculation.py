@@ -107,9 +107,9 @@ def captured(monkeypatch):
     calls: list[list] = []
     real = AV.verify_signature_sets_async
 
-    def spy(sets):
+    def spy(sets, **kw):
         calls.append(list(sets))
-        return real(sets)
+        return real(sets, **kw)
 
     monkeypatch.setattr(AV, "verify_signature_sets_async", spy)
     return calls

@@ -369,7 +369,7 @@ class TestSyncCommitteeService:
             store.add_validator(LocalKeystore(interop_secret_key(i)))
         vc = ValidatorClient(store, BeaconNodeFallback([node]), MINIMAL, h.spec)
 
-        slots = 2 * MINIMAL.slots_per_epoch + 4
+        slots = MINIMAL.slots_per_epoch + 3
         for slot in range(1, slots + 1):
             h.chain.slot_clock.set_slot(slot)
             h.chain.on_tick()

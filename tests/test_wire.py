@@ -193,12 +193,16 @@ class TestCliWire:
 
         boot = Bootnode().start()
         servers = []
+        # genesis four slots before now: SystemSlotClock is already past
+        # slots 1-2, so they import without clock manipulation
+        seconds_per_slot = ChainSpec.interop().seconds_per_slot
+        genesis_time = int(time.time()) - 4 * seconds_per_slot
         try:
             def bn_args(peer):
                 return argparse.Namespace(
                     network="interop", preset="minimal",
                     altair_fork_epoch=None, datadir=None, http_port=0,
-                    interop_validators=16, genesis_time=1000,
+                    interop_validators=16, genesis_time=genesis_time,
                     genesis="interop", listen_port=0,
                     bootnode=f"{boot.host}:{boot.port}", peer_id=peer,
                 )
@@ -212,8 +216,6 @@ class TestCliWire:
             producer = StateHarness(
                 16, MINIMAL, node_a.chain.spec, sign=False
             )
-            # genesis_time=1000 is long past, so SystemSlotClock is far
-            # ahead and slots 1-2 import without clock manipulation
             for slot in (1, 2):
                 parent = node_a.chain._states[node_a.chain.head_root]
                 signed, _ = producer.produce_block(

@@ -198,7 +198,7 @@ def verify_body_grouped(
     multi-chip form: the per-set arrays (pk/sig/scalars/real) and the
     (n, m) membership mask shard on the sets axis; each shard reduces its
     LOCAL weighted per-set pubkeys into per-message PARTIAL sums (a
-    masked broadcast + one scanned halving body), one all_gather ships
+    masked broadcast + one `sum_points`), one all_gather ships
     the m_b partial points (tiny: m_b is a handful) and every chip sums
     them into the full per-message pubkeys. The m_b + 1 Miller pairs then
     run REPLICATED on every chip -- at mega-batch sizes the per-set work
@@ -223,7 +223,7 @@ def verify_body_grouped(
     rpk = TC.scalar_mul_u64(agg_pk, scalars, TC.FP)
 
     # local per-message partial sums: mask each set's weighted pubkey into
-    # its message row (non-members -> infinity), then one halving body
+    # its message row (non-members -> infinity), then one point sum
     inf_g1 = TC.infinity(TC.FP)
     rows = jnp.where(
         jnp.moveaxis(member, 0, -1)[..., None, None], rpk[None], inf_g1
@@ -362,8 +362,8 @@ def verify_device(u, h_idx, pk_jac, sig_jac, scalars, real):
 def _stage_group(rpk_aff, rpk_inf, grid_idx, grid_real):
     """Per-message pubkey aggregation: gather the weighted per-set G1
     points into the (m_b, g_b) group grid (padding slots masked to
-    infinity), lift to projective, and sum each message's row -- ONE
-    scanned halving body over the group axis, batched over messages.
+    infinity), lift to projective, and sum each message's row -- one
+    `sum_points` over the group axis, batched over messages.
     Returns affine points + inf mask sized for the m_b-pair Miller
     stage."""
     rows_aff = jnp.take(rpk_aff, grid_idx, axis=0)  # (m_b, g_b, 2, W)

@@ -30,8 +30,10 @@ subgroup checks at blst.rs:72-82) with branchless, batch-first kernels:
     curve_ref.g2_subgroup_check_psi).
   * Cross-set point sums (pubkey aggregation, the weighted-signature sum)
     use `sum_points`: a halving reduction expressed as ONE scanned
-    body instead of log2(n) inlined tree levels, trading ~2x redundant
-    adds (on infinity padding) for log-fold smaller programs.
+    body instead of log2(m) inlined tree levels. Its fixed-shape step adds
+    m/2 pairs every time, (m/2) log2(m) adds for m - 1 (4.5x at m = 512),
+    so where a step is wide (`fold_rows`) the rows are first folded in
+    chunks by a second scanned add, which leaves about m - 1.
 
 Differentially tested against the pure-Python oracle (curve_ref.py) in
 tests/test_tpu_curve.py.
@@ -39,6 +41,7 @@ tests/test_tpu_curve.py.
 
 from __future__ import annotations
 
+import math
 import os as _os
 
 import numpy as np
@@ -49,6 +52,7 @@ import jax.numpy as jnp
 from ..constants import BLS_X, G1_X, G1_Y, G2_X, G2_Y, P, R
 from ..curve_ref import Point, _PSI_CX, _PSI_CY
 from ..fields_ref import Fp, Fp2
+from ....utils import metrics
 from . import limbs as L
 from . import tower as T
 
@@ -324,31 +328,88 @@ if _os.environ.get("LIGHTHOUSE_TPU_PALLAS") == "1":  # pragma: no cover
 # --- cross-set reductions ---------------------------------------------------
 
 
-def sum_points(p, F):
-    """EC sum over axis 0 (any length; pads to a power of two with
-    infinity) as ONE scanned halving body: each iteration adds adjacent
-    pairs into the front half and refills the back half with infinity.
-    log2(n) iterations; compiled program size is a single complete add
-    regardless of n."""
+# Narrowest complete add, in points, that a folding step may take: below
+# it a point sum keeps the halving scan, whose fewer sequential steps win
+# where an add is latency-bound. Device ms on a TPU v5 lite
+# (`python -m tools.point_sum_widths`, median of 7):
+#   one G1 add at 4,096 / 8,192 / 16,384 / 32,768 / 65,536 points:
+#     1.75 / 8.18 / 17.23 / 33.24 / 65.90 (0.43 us a point at 4,096,
+#     1.0 us from 8,192 up)
+#   512 rows x 256 (the block's key sums): halving 232.80; folded in
+#     chunks of 16 / 32 / 64 / 128 / 256 rows (FOLD_WIDTH 4,096 ... 65,536):
+#     20.81 / 31.08 / 57.66 / 84.94 / 129.75
+#   256 rows x 128 (the block's message groups): halving 52.62; chunks of
+#     32 / 64 / 128 rows: 6.51 / 10.02 / 20.14
+FOLD_WIDTH = 4096
+
+
+def fold_rows(m: int, width: int) -> int:
+    """Rows per chunk where the sum of `m` rows (a power of two) of `width`
+    points each folds, or 0 where it keeps the halving scan: it folds once
+    a halving step is FOLD_WIDTH adds wide, in chunks of the fewest rows
+    (a power of two) that make a step that wide."""
+    if m // 2 * width < FOLD_WIDTH:
+        return 0
+    b = 1
+    while b * width < FOLD_WIDTH:
+        b *= 2
+    return b
+
+
+def _pad_pow2(p, F):
     n = p.shape[0]
-    m = 1
-    while m < n:
-        m *= 2
+    m = 1 << max(n - 1, 0).bit_length()
+    if m == n:
+        return p
     batch = p.shape[1 : p.ndim - F.coord_ndim - 1]
-    if m > n:
-        p = jnp.concatenate([p, infinity(F, (m - n,) + batch)], axis=0)
+    return jnp.concatenate([p, infinity(F, (m - n,) + batch)], axis=0)
+
+
+def _halving_sum(p, F):
+    """Sum over axis 0 (a power of two) as ONE scanned halving body: each
+    iteration adds adjacent pairs into the front half and refills the back
+    half with infinity. log2(m) iterations of m/2 adds each."""
+    m = p.shape[0]
     if m == 1:
         return p[0]
-    half = m // 2
-    pad = infinity(F, (half,) + batch)
-    steps = m.bit_length() - 1
+    batch = p.shape[1 : p.ndim - F.coord_ndim - 1]
+    pad = infinity(F, (m // 2,) + batch)
 
     def body(acc, _):
         s = add(acc[0::2], acc[1::2], F)
         return jnp.concatenate([s, pad], axis=0), None
 
-    out, _ = jax.lax.scan(body, p, None, length=steps)
+    out, _ = jax.lax.scan(body, p, None, length=m.bit_length() - 1)
     return out[0]
+
+
+def fold_sum(p, b, F):
+    """EC sum over axis 0 (any length; pads to a power of two with
+    infinity): the m/b chunks of `b` rows (a power of two) are accumulated
+    by one scanned add, m/b - 1 steps of b adds per column, then the halving
+    scan sums the b rows left."""
+    p = _pad_pow2(p, F)
+    m = p.shape[0]
+    if b < m:
+        chunks = p.reshape((m // b, b) + p.shape[1:])
+        p, _ = jax.lax.scan(
+            lambda acc, c: (add(acc, c, F), None), chunks[0], chunks[1:]
+        )
+    return _halving_sum(p, F)
+
+
+def sum_points(p, F):
+    """EC sum over axis 0 (any length; pads to a power of two with
+    infinity). Folds first where `fold_rows` says the halving steps are
+    wide; the rule reads only the input's shape, so every caller adapts.
+    The compiled program holds one complete add, or two where it folds."""
+    p = _pad_pow2(p, F)
+    batch = p.shape[1 : p.ndim - F.coord_ndim - 1]
+    b = fold_rows(p.shape[0], math.prod(batch))
+    if not b:
+        return _halving_sum(p, F)
+    metrics.TPU_POINT_SUM_FOLDS.inc()
+    return fold_sum(p, b, F)
 
 
 # --- affine conversion ------------------------------------------------------

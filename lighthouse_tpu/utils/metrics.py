@@ -503,6 +503,11 @@ TPU_COMPILE_CACHE_MISSES = REGISTRY.counter(
     "tpu_compile_cache_misses_total",
     "Batches marshalled to a NEW bucketed shape (XLA compile expected)",
 )
+TPU_POINT_SUM_FOLDS = REGISTRY.counter(
+    "tpu_point_sum_folds_total",
+    "Point sums traced on the folding path (tpu/curve.py sum_points): "
+    "counted once per compiled program, not per launch",
+)
 TPU_WARM_COMPILE_SECONDS = REGISTRY.labeled_gauge(
     "tpu_warm_compile_seconds",
     "Wall seconds the AOT warm pass spent compiling (or cache-loading) "

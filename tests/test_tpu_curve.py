@@ -3,7 +3,8 @@
 Covers the batched point ops that replace blst's POINTonE1/POINTonE2
 (reference crypto/bls/src/impls/blst.rs:72-106): add/double incl. all
 exceptional cases, mixed add, the runtime-64-bit weight ladder, affine
-conversion, psi, and the subgroup/on-curve checks.
+conversion, psi, the subgroup/on-curve checks, and the cross-row point
+sum (halving scan and fold).
 
 Structure note: every op is wrapped in ONE module-level jitted kernel at
 ONE batch shape (B = 8), so the suite pays each XLA compile exactly once
@@ -11,6 +12,7 @@ ONE batch shape (B = 8), so the suite pays each XLA compile exactly once
 computed host-side per case.
 """
 
+import math
 import random
 
 import numpy as np
@@ -22,6 +24,7 @@ from lighthouse_tpu.crypto.bls.constants import B2, P, R
 from lighthouse_tpu.crypto.bls.fields_ref import Fp, Fp2
 from lighthouse_tpu.crypto.bls.tpu import curve as TC
 from lighthouse_tpu.crypto.bls.tpu import limbs as L
+from lighthouse_tpu.utils import metrics
 
 rng = random.Random(0xC0FFEE)
 B = 8  # unified batch size -> one compile per kernel
@@ -35,6 +38,8 @@ jmul1 = jax.jit(lambda p, s: TC.scalar_mul_u64(p, s, TC.FP))
 jaff1 = jax.jit(TC.to_affine_g1)
 joncurve1 = jax.jit(TC.on_curve_g1)
 jsubgroup1 = jax.jit(TC.g1_subgroup_check)
+jsum1 = jax.jit(lambda p: TC.sum_points(p, TC.FP))
+jfold1 = jax.jit(lambda p: TC.fold_sum(p, 2, TC.FP))
 
 jadd2 = jax.jit(lambda p, q: TC.add(p, q, TC.FP2))
 jmadd2 = jax.jit(lambda p, q, qi: TC.add_mixed(p, q, qi, TC.FP2))
@@ -182,3 +187,77 @@ class TestG2:
         got = np.asarray(jsubgroup2(dev))
         assert got.tolist() == [True] * (B - 2) + [False, True]
         assert np.asarray(joncurve2(dev)).all()
+
+
+def _sum_adds(m, width):
+    """Complete adds of `sum_points` over m rows (a power of two) of
+    `width` points: the fold's chunk steps plus the halving steps."""
+    b = TC.fold_rows(m, width)
+    if not b:
+        return m // 2 * int(math.log2(m)) * width
+    return (m // b - 1) * b * width + b // 2 * int(math.log2(b)) * width
+
+
+class TestPointSum:
+    def test_fold_and_halving_match_oracle(self):
+        # columns: generic, a row of infinity, mixed infinity, P + (-P),
+        # all infinity; 5 rows pad to 8, so the fold at chunk 2 scans 3
+        # steps before its halving
+        n = 5
+        pts = rand_g1(n)
+        a = pts[0]
+        cols = [
+            pts,
+            [INF1] * n,
+            [INF1, pts[1], INF1, pts[3], INF1],
+            [a, -a, INF1, INF1, a],
+            [a, -a, pts[2], -pts[2], INF1],
+            [INF1, INF1, INF1, INF1, pts[4]],
+            [pts[4], INF1, INF1, INF1, INF1],
+            [a] * n,
+        ]
+        dev = jnp.stack([TC.g1_pack(c) for c in cols], axis=1)  # (n, B, 3, W)
+        want = []
+        for c in cols:
+            acc = INF1
+            for q in c:
+                acc = acc + q
+            want.append(acc)
+        assert want[3] == a and want[4] == INF1 and want[1] == INF1
+        assert unpack_g1(jfold1(dev)) == want
+        assert unpack_g1(jsum1(dev)) == want
+
+    def test_single_row_is_identity(self):
+        dev = TC.g1_pack(rand_g1(B))[None]
+        for got in (TC.fold_sum(dev, 2, TC.FP), TC.sum_points(dev, TC.FP)):
+            np.testing.assert_array_equal(np.asarray(got), dev[0])
+
+    def test_fold_rule_at_the_cells_shapes(self):
+        # gossip: key sum 4 x 64, signature sum 64 x 1, map_to_g2's 3-point
+        # sum 4 x 64; block signature sum 256 x 1
+        for m, width in ((4, 64), (64, 1), (256, 1), (4, 128)):
+            assert TC.fold_rows(m, width) == 0
+            assert _sum_adds(m, width) == m // 2 * int(math.log2(m)) * width
+        # block key sum: 512 keys x 256 sets; grouping: 256 x 128 messages
+        assert TC.fold_rows(512, 256) * 256 == TC.FOLD_WIDTH == 4096
+        assert TC.fold_rows(512, 256) == 16
+        assert _sum_adds(512, 256) == 135_168  # halving: 589,824
+        assert TC.fold_rows(256, 128) == 32
+        assert _sum_adds(256, 128) == 38_912  # halving: 131,072
+        # the fold starts where a halving step reaches FOLD_WIDTH adds
+        assert TC.fold_rows(2 * TC.FOLD_WIDTH, 1) == TC.FOLD_WIDTH
+        assert TC.fold_rows(TC.FOLD_WIDTH, 1) == 0
+
+    def test_fold_counter_counts_traced_folds(self):
+        def traced(shape):
+            before = metrics.TPU_POINT_SUM_FOLDS.value
+            jax.eval_shape(
+                lambda p: TC.sum_points(p, TC.FP),
+                jax.ShapeDtypeStruct(shape + (3, TC.W), jnp.int32),
+            )
+            return metrics.TPU_POINT_SUM_FOLDS.value - before
+
+        assert traced((512, 256)) == 1
+        assert traced((256, 128)) == 1
+        assert traced((4, 64)) == 0
+        assert traced((64,)) == 0
